@@ -108,7 +108,7 @@ def _bench_backup(rows, label, idx, val, cost, v):
     gamma = 0.99
     # tune eagerly first: the fused timing below traces ops.ell_backup
     # inside an outer jit, where the tuner can only consult its cache
-    impl = ops._resolve(None)
+    impl = ops.resolve_impl(None)
     bn = (ops.backup_block_rows(n, m, k, v.shape[0], val.dtype)
           if impl == "blocked" else None)
     fused = jax.jit(lambda i, w, c, g, u: ops.ell_backup(i, w, c, g, u))
@@ -202,7 +202,9 @@ def _bench_flag_bundles(rows, side: int) -> None:
     child = _CHILD.format(src=os.path.join(root, "src"), side=side)
     base_us = None
     for bundle in (None, "cpu-single", "cpu-host"):
-        env = dict(os.environ)
+        # the bundles are CPU flags; a child must never take an accelerator
+        # (and this parent has already imported jax)
+        env = dict(os.environ, JAX_PLATFORMS="cpu")
         if bundle is not None:
             env["XLA_FLAGS"] = xla_flags.merged_flags(
                 bundle, env.get("XLA_FLAGS", ""))

@@ -135,7 +135,8 @@ print("RESULT " + json.dumps(out))
 
 
 def _child(script: str, *argv: object) -> dict:
-    env = dict(os.environ, PYTHONPATH="src")
+    # the children fake CPU devices; they must never take an accelerator
+    env = dict(os.environ, PYTHONPATH="src", JAX_PLATFORMS="cpu")
     out = subprocess.run([sys.executable, "-c", script,
                           *map(str, argv)],
                          env=env, capture_output=True, text=True,

@@ -1,4 +1,6 @@
 import os
+# fake CPU devices only: never take an attached accelerator
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=512")
 
 """Roofline-term extraction (per arch x shape cell, single-pod mesh).

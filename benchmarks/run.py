@@ -97,4 +97,6 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.utils import compile_cache
+    compile_cache.enable()
     main()

@@ -248,9 +248,9 @@ _SPECS = [
                "divergence between chunks and hot-swap to the next method "
                "in the escalation chain, resuming from the current state"),
     OptionSpec("-kernel_impl", str, None,
-               "kernel implementation (auto = blocked XLA on CPU, Pallas "
-               "on TPU, with autotuned tiles); '-impl' is accepted as an "
-               "alias",
+               "kernel implementation (auto = blocked XLA with autotuned "
+               "tiles on CPU, the fused rows-last XLA chain on TPU); "
+               "'-impl' is accepted as an alias",
                choices=("auto", "xla", "blocked", "pallas",
                         "pallas_interpret"),
                nullable=True),
@@ -282,7 +282,8 @@ _SPECS = [
                validate=_positive("async_sweeps")),
     # ---- placement (owned by the session layer) ----------------------------
     OptionSpec("-xla_flag_bundle", str, None,
-               "named XLA_FLAGS bundle applied at session start "
+               "named compiler-flag bundle applied at session start: "
+               "cpu-* into XLA_FLAGS, tpu-* into LIBTPU_INIT_ARGS "
                "(repro.utils.xla_flags)",
                choices_fn=lambda: tuple(_xla_flags.bundle_names()),
                choices_doc=" \\| ".join(

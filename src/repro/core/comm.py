@@ -29,7 +29,6 @@ from typing import Optional, Sequence, Union
 import jax
 import jax.numpy as jnp
 
-from repro.utils import jax_compat
 
 AxisName = Union[str, Sequence[str], None]
 
@@ -125,10 +124,10 @@ class Axes:
         if self.state is None:
             return 1
         if isinstance(self.state, str):
-            return jax_compat.axis_size(self.state)
+            return jax.lax.axis_size(self.state)
         out = 1
         for name in self.state:
-            out *= jax_compat.axis_size(name)
+            out *= jax.lax.axis_size(name)
         return out
 
     # ---- fleet-axis collectives -------------------------------------------------

@@ -50,7 +50,6 @@ from repro.core.ipi import IPIOptions, SolveState
 from repro.core.mdp import (DenseMDP, EllMDP, MatrixFreeMDP, MDP, gammas_of,
                             stack_mdps)
 from repro.utils import checkpoint as ckpt
-from repro.utils.jax_compat import shard_map as _shard_map
 
 
 @dataclasses.dataclass
@@ -304,30 +303,30 @@ def _make_runners(dev_mdp, opts: IPIOptions, mesh, axes: Axes, batch,
         if len(_RUN_CHUNK_CACHE) > 64:   # bound growth: drop the oldest
             _RUN_CHUNK_CACHE.pop(next(iter(_RUN_CHUNK_CACHE)))
         run_chunk = jax.jit(
-            _shard_map(
+            jax.shard_map(
                 partial(ipi.solve_chunk, opts=opts, axes=axes),
                 mesh=mesh,
                 in_specs=in_specs,
-                out_specs=state_specs),
+                out_specs=state_specs, check_vma=False),
         )
         _RUN_CHUNK_CACHE[key] = run_chunk
 
     def init(v0):
         if v0 is None:
             f = jax.jit(
-                _shard_map(
+                jax.shard_map(
                     lambda m: ipi.init_state(m, axes, opts, n_true=n_true),
                     mesh=mesh, in_specs=(mdp_specs,),
-                    out_specs=state_specs))
+                    out_specs=state_specs, check_vma=False))
             return f(dev_mdp)
         v_spec = P(*lead, axes.state)
         v0 = jax.device_put(jnp.asarray(v0), NamedSharding(mesh, v_spec))
         f = jax.jit(
-            _shard_map(
+            jax.shard_map(
                 lambda m, v: ipi.init_state(m, axes, opts, v,
                                             n_true=n_true),
                 mesh=mesh, in_specs=(mdp_specs, v_spec),
-                out_specs=state_specs))
+                out_specs=state_specs, check_vma=False))
         return f(dev_mdp, v0)
 
     return run_chunk, init

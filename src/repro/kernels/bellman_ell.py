@@ -79,7 +79,7 @@ def _backup_kernel(gamma_ref, idx_ref, val_ref, cost_ref, v_ref,
     @pl.when(j == v_tiles - 1)
     def _reduce_actions():
         gamma = gamma_ref[0, 0]
-        pv = jnp.sum(part_ref[...], axis=-1)
+        pv = ref.ksum(part_ref[...])
         # pin_rounding matches ref.ell_qvalues' pinned double rounding of
         # cost + gamma*pv (see ref.py); plain jnp ops, so it lowers on every
         # Pallas backend.

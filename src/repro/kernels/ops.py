@@ -1,14 +1,19 @@
 """Jit'd dispatch layer over the kernels.
 
 Implementations:
-  * ``"auto"``             — pick per backend: ``pallas`` on TPU, ``blocked``
-                             elsewhere; tile sizes come from the autotuner
-                             (:mod:`repro.kernels.tuning`).  The default.
-  * ``"xla"``              — pure-jnp reference (ref.py), one fused chain.
+  * ``"auto"``             — pick per backend: ``xla`` on TPU, ``blocked``
+                             elsewhere; the blocked scan chunk comes from the
+                             autotuner (:mod:`repro.kernels.tuning`).  The
+                             default.
+  * ``"xla"``              — pure-jnp reference (ref.py), one fused chain,
+                             rows-last so the TPU keeps it lane-dense.
   * ``"blocked"``          — cache-blocked XLA: row-chunked scan whose chunk
                              working set stays in cache (ref.py blocked
                              variants; bit-identical to ``xla``).
-  * ``"pallas"``           — Pallas TPU kernels (compiled; TPU target).
+  * ``"pallas"``           — Pallas TPU kernels.  Mosaic refuses them on a
+                             real TPU (their in-kernel ``jnp.take`` is a 1-D
+                             gather, Mosaic lowers only 2-D ones), and the
+                             refusal is raised as is: nothing falls back.
   * ``"pallas_interpret"`` — Pallas kernels run through the interpreter
                              (CPU-correctness validation; used by tests).
 
@@ -42,6 +47,12 @@ from . import ref, tuning
 _DEFAULT_IMPL = "auto"
 _VALID = ("auto", "xla", "blocked", "pallas", "pallas_interpret")
 
+# What "auto" runs, per backend.  TPU: the blocked scan slices rows off the
+# lane axis of the TPU's (n, m, K) layout and pads each chunk 16x, which
+# does not fit a real table; the rows-last fused chain does.
+AUTO_IMPL = {"tpu": "xla"}
+AUTO_IMPL_DEFAULT = "blocked"
+
 # Scan-chunk candidates for the blocked implementation (rows per chunk).
 BLOCK_ROWS_CANDIDATES = (31_250, 62_500, 125_000, 250_000, 500_000)
 
@@ -60,11 +71,13 @@ def get_default_impl() -> str:
     return _DEFAULT_IMPL
 
 
-def _resolve(impl: str | None) -> str:
+def resolve_impl(impl: str | None = None) -> str:
+    """The implementation ``impl`` (default: the process default) runs as
+    on this backend."""
     impl = impl or _DEFAULT_IMPL
     assert impl in _VALID, impl
     if impl == "auto":
-        return "pallas" if jax.default_backend() == "tpu" else "blocked"
+        return AUTO_IMPL.get(jax.default_backend(), AUTO_IMPL_DEFAULT)
     return impl
 
 
@@ -177,7 +190,7 @@ def _ell_backup(idx, val, cost, gamma, v, impl, block_rows):
 def ell_backup(idx, val, cost, gamma, v, *, impl: str | None = None,
                block_rows: int | None = None):
     """Fused Bellman backup on an ELL block -> (v_new (n,), argmin (n,) int32)."""
-    impl = _resolve(impl)
+    impl = resolve_impl(impl)
     if val.ndim == 4:
         if val.shape[0] == 1:
             tv, am = _ell_backup(_sq(idx, 4), val[0], cost[0], gamma,
@@ -206,7 +219,7 @@ def ell_backup_chunk(idx, val, cost, gamma, v, *, impl: str | None = None):
     Bit-identical to running the materialized kernel over the same rows:
     the math is row-independent, so any chunking yields the same bits.
     """
-    impl = _resolve(impl)
+    impl = resolve_impl(impl)
     if impl == "xla":
         return ref.ell_backup(idx, val, cost, gamma, v)
     if impl == "blocked":
@@ -232,7 +245,7 @@ def _ell_qvalues(idx, val, cost, gamma, v, impl, block_rows):
 @functools.partial(jax.jit, static_argnames=("impl", "block_rows"))
 def ell_qvalues(idx, val, cost, gamma, v, *, impl: str | None = None,
                 block_rows: int | None = None):
-    impl = _resolve(impl)
+    impl = resolve_impl(impl)
     if val.ndim == 4:
         if val.shape[0] == 1:
             return _ell_qvalues(_sq(idx, 4), val[0], cost[0], gamma,
@@ -260,7 +273,7 @@ def _ell_matvec(idx, val, x, impl, block_rows):
 def ell_matvec(idx, val, x, *, impl: str | None = None,
                block_rows: int | None = None):
     """Policy-restricted SpMV y = P_pi @ x on (n, K) ELL rows."""
-    impl = _resolve(impl)
+    impl = resolve_impl(impl)
     if val.ndim == 3:
         if val.shape[0] == 1:
             return _ell_matvec(_sq(idx, 3), val[0], _sq(x, 2), impl,
@@ -282,7 +295,7 @@ def _dense_backup(p, cost, gamma, v, impl):
 
 @functools.partial(jax.jit, static_argnames=("impl",))
 def dense_backup(p, cost, gamma, v, *, impl: str | None = None):
-    impl = _resolve(impl)
+    impl = resolve_impl(impl)
     if p.ndim == 4:
         if p.shape[0] == 1:
             tv, am = _dense_backup(p[0], cost[0], gamma, _sq(v, 2), impl)

@@ -1,9 +1,10 @@
 """Pure-jnp oracles for every kernel in this package.
 
-These are the semantic ground truth: the Pallas kernels must match them
-bit-for-bit up to float tolerance (tests/test_kernels.py sweeps shapes and
-dtypes against these).  They are also the XLA fallback implementation used on
-non-TPU backends.
+These are the semantic ground truth: every other implementation matches
+them bit for bit (tests/test_kernels.py and tests/test_kernels_tiled.py sweep
+shapes and dtypes against them).  They are also the XLA implementations:
+``ell_backup``/``ell_matvec`` are what ``-kernel_impl auto`` runs on TPU, and
+the cache-blocked variants below are its choice elsewhere.
 """
 
 from __future__ import annotations
@@ -31,16 +32,38 @@ def pin_rounding(x: jax.Array) -> jax.Array:
     return jnp.where(x == x, x, 0.0 * x)
 
 
-def ell_gather_dot(idx: jax.Array, val: jax.Array, v: jax.Array) -> jax.Array:
+def ksum(x: jax.Array, axis: int = -1) -> jax.Array:
+    """Sum over a short axis (the ELL ``K`` slots) strictly left to right.
+
+    A reduce op leaves its association order to the backend (XLA:TPU and
+    XLA:CPU tree differently, and differently per layout), so the row-major
+    and rows-last formulations below would disagree at the last ulp.  A
+    chain of binary adds has one order everywhere, which keeps every
+    implementation bit-identical on every backend.
+    """
+    x = jnp.moveaxis(x, axis, 0)
+    acc = x[0]
+    for k in range(1, x.shape[0]):
+        acc = acc + x[k]
+    return acc
+
+
+def ell_gather_dot(idx: jax.Array, val: jax.Array, v: jax.Array, *,
+                   k_axis: int = -1) -> jax.Array:
     """sum_k val[..., k] * v[idx[..., k]]  — the ELL row-gather dot.
 
-    idx: (..., K) int32 global column ids; val: (..., K); v: (n_cols,).
-    Returns (...,) accumulated in >= f32 (f64 when v is f64).
+    idx: int32 global column ids with the K slots on ``k_axis``; val: same
+    shape; v: (n_cols,).  Returns idx's shape without ``k_axis``,
+    accumulated in >= f32 (f64 when v is f64).
     """
     dt = _acc_dtype(val, v)
     gathered = jnp.take(v, idx, axis=0)
     prod = pin_rounding(val.astype(dt) * gathered.astype(dt))
-    return jnp.sum(prod, axis=-1)
+    return ksum(prod, k_axis)
+
+
+def _rows_last(x: jax.Array) -> jax.Array:
+    return jnp.moveaxis(x, 0, -1)
 
 
 def ell_qvalues(idx: jax.Array, val: jax.Array, cost: jax.Array, gamma: float,
@@ -50,16 +73,28 @@ def ell_qvalues(idx: jax.Array, val: jax.Array, cost: jax.Array, gamma: float,
     return cost.astype(pv.dtype) + pin_rounding(gamma * pv)
 
 
+def _qvalues_rows_last(idx, val, cost, gamma, v) -> jax.Array:
+    """``ell_qvalues`` computed as ``(m, n)``: the row axis is moved last
+    before the gather.  XLA:TPU stores an ``(n, m, K)`` table with n in the
+    128-wide lane dimension, so this view is free and the gathered block
+    stays lane-dense; the row-major gather's ``(n, m, K)`` output would pad
+    K = 8 out to 128 lanes (16x the table's bytes at m = 16, K = 8)."""
+    pv = ell_gather_dot(_rows_last(idx), _rows_last(val), v, k_axis=-2)
+    return _rows_last(cost).astype(pv.dtype) + pin_rounding(gamma * pv)
+
+
 def ell_backup(idx: jax.Array, val: jax.Array, cost: jax.Array, gamma: float,
                v: jax.Array) -> tuple[jax.Array, jax.Array]:
-    """Fused Bellman backup: (min_a Q, argmin_a Q) with smallest-index tie-break."""
-    q = ell_qvalues(idx, val, cost, gamma, v)
-    return jnp.min(q, axis=-1), jnp.argmin(q, axis=-1).astype(jnp.int32)
+    """Fused Bellman backup: (min_a Q, argmin_a Q) with smallest-index
+    tie-break.  Rows-last, like :func:`ell_matvec`: the one XLA chain that
+    fits a real-size table on the TPU."""
+    q = _qvalues_rows_last(idx, val, cost, gamma, v)
+    return jnp.min(q, axis=0), jnp.argmin(q, axis=0).astype(jnp.int32)
 
 
 def ell_matvec(idx: jax.Array, val: jax.Array, x: jax.Array) -> jax.Array:
     """y(s) = sum_{s'} P_pi(s, s') x(s') on policy-restricted ELL rows (n, K)."""
-    return ell_gather_dot(idx, val, x)
+    return ell_gather_dot(_rows_last(idx), _rows_last(val), x, k_axis=-2)
 
 
 # ---------------------------------------------------------------------------

@@ -18,6 +18,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import ref
+
 DEFAULT_TILE_N = 512
 DEFAULT_TILE_V = 128 * 1024
 
@@ -45,7 +47,7 @@ def _spmv_kernel(idx_ref, val_ref, x_ref, out_ref, part_ref,
 
     @pl.when(j == v_tiles - 1)
     def _reduce():
-        out_ref[...] = jnp.sum(part_ref[...], axis=-1)
+        out_ref[...] = ref.ksum(part_ref[...])
 
 
 @functools.partial(jax.jit,

@@ -157,9 +157,7 @@ def tune(kernel: str, backend: str, n: int, m: int, k: int, dtype: Any,
     if (not _state.enabled or bench is None
             or n * m * k < MIN_TUNE_ELEMS or len(candidates) <= 1):
         return default
-    import jax
-
-    if not jax.core.trace_state_clean():
+    if _tracing():
         # The dispatch layer is being traced inside an enclosing jit:
         # running the candidates here would stage them into that trace
         # instead of timing them.  Fall back to the default and leave the
@@ -180,6 +178,15 @@ def tune(kernel: str, backend: str, n: int, m: int, k: int, dtype: Any,
     if timings:
         record(key, {"choice": best, "timings_s": timings})
     return best
+
+
+def _tracing() -> bool:
+    """True while a jax transformation is tracing the caller: any operation
+    issued now is staged into that trace and comes back as a tracer."""
+    import jax
+    import jax.numpy as jnp
+
+    return isinstance(jnp.zeros(()), jax.core.Tracer)
 
 
 def measure(fn: Callable[[], Any]) -> float:

@@ -1,4 +1,6 @@
 import os
+# fake CPU devices only: never take an attached accelerator
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
@@ -64,9 +66,7 @@ def collective_bytes(hlo_text: str) -> dict:
 
 
 def analyze(compiled, lower_s, compile_s) -> dict:
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):   # <= 0.4.x: one dict per device
-        cost = cost[0] if cost else {}
+    cost = compiled.cost_analysis() or {}
     mem = compiled.memory_analysis()
     txt = compiled.as_text()
     coll = collective_bytes(txt)
@@ -243,14 +243,13 @@ def run_mdp_cell(name: str, mesh) -> dict:
             # sync methods carry an empty stale window (async_vi state only)
             win=jax.ShapeDtypeStruct((0,), jnp.float32,
                                      sharding=sspec_tree.win))
-        from repro.utils.jax_compat import shard_map as _shard_map
         fn = jax.jit(
-            _shard_map(
+            jax.shard_map(
                 partial(ipi.solve_chunk, opts=opts, axes=axes),
                 mesh=mesh,
                 in_specs=(partition.mdp_pspecs(mdp_abs, axes),
                           state_specs, P(), P()),
-                out_specs=state_specs))
+                out_specs=state_specs, check_vma=False))
         t0 = time.time()
         lowered = fn.lower(mdp_sds, state_sds,
                            jax.ShapeDtypeStruct((), jnp.int32),

@@ -10,6 +10,8 @@ simulates it in-process by re-sharding the restored state onto a new mesh.
 
 import os
 
+# fake CPU devices only: never take an attached accelerator
+os.environ["JAX_PLATFORMS"] = "cpu"
 if "--xla_force_host_platform_device_count" not in \
         os.environ.get("XLA_FLAGS", ""):
     os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")

@@ -12,11 +12,9 @@ import numpy as np
 
 
 def mesh_kwargs(n_axes: int, **extra) -> dict:
-    """``jax.make_mesh`` kwargs, with ``axis_types`` only where the
-    installed jax supports it (absent pre-0.5: Auto is the default there)."""
-    if hasattr(jax.sharding, "AxisType"):
-        extra["axis_types"] = (jax.sharding.AxisType.Auto,) * n_axes
-    return extra
+    """``jax.make_mesh`` kwargs: every axis ``Auto`` (``make_mesh`` defaults
+    to ``Explicit``; the solver places its collectives by hand)."""
+    return dict(extra, axis_types=(jax.sharding.AxisType.Auto,) * n_axes)
 
 
 def make_production_mesh(*, multi_pod: bool = False):

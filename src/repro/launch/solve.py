@@ -208,4 +208,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.utils import compile_cache
+    compile_cache.enable()
     raise SystemExit(main())

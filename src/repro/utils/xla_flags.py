@@ -7,11 +7,14 @@ instead of exporting raw flags, and so A/B benchmarks
 (``benchmarks/run.py --only kernels``) can sweep them reproducibly.
 
 Flags must reach XLA before the backend initializes.  ``apply_bundle``
-merges the bundle into ``os.environ["XLA_FLAGS"]`` (existing flags are kept;
-bundle flags are appended, and XLA's last-one-wins parsing makes the bundle
-take precedence on conflicts).  If the JAX backend is already up, the merge
-still happens — useful for subprocess benchmarking — but a warning explains
-that the current process will not see the change.
+merges the bundle into the environment variable its compiler reads
+(:func:`env_var`): ``XLA_FLAGS`` for the CPU bundles, ``LIBTPU_INIT_ARGS``
+for the TPU ones, whose flags only libtpu knows (jaxlib aborts on them in
+``XLA_FLAGS``).  Existing flags are kept; bundle flags are appended, and
+last-one-wins parsing makes the bundle take precedence on conflicts.  If
+the JAX backend is already up, the merge still happens — useful for
+subprocess benchmarking — but a warning explains that the current process
+will not see the change.
 """
 
 from __future__ import annotations
@@ -36,10 +39,7 @@ BUNDLES: dict[str, dict[str, str]] = {
     # concurrency-optimized thunk scheduler lets XLA:CPU run the value-window
     # collective concurrently with the interior-row backup that does not
     # depend on it.  On TPU the same overlap needs the async-collective
-    # family instead — use "tpu-collectives" there (the
-    # xla_enable_async_all_gather / xla_enable_async_collective_permute
-    # flags only exist in TPU-capable XLA builds and are fatal on CPU-only
-    # ones, so they must not appear here).
+    # family instead — use "tpu-collectives" there.
     "cpu-overlap": {
         "xla_cpu_enable_concurrency_optimized_scheduler": "true",
         "xla_cpu_enable_fast_min_max": "false",
@@ -71,6 +71,12 @@ def bundle(name: str) -> dict[str, str]:
             f"available: {', '.join(bundle_names())}") from None
 
 
+def env_var(name: str) -> str:
+    """The environment variable the bundle's compiler reads its flags from."""
+    bundle(name)
+    return "LIBTPU_INIT_ARGS" if name.startswith("tpu-") else "XLA_FLAGS"
+
+
 def render(name: str) -> str:
     """The bundle as an XLA_FLAGS fragment: ``--flag=value ...``."""
     return " ".join(f"--{k}={v}" for k, v in bundle(name).items())
@@ -86,10 +92,11 @@ def backend_initialized() -> bool:
 
 
 def merged_flags(name: str, existing: str | None = None) -> str:
-    """Existing XLA_FLAGS with the bundle appended (bundle wins conflicts)."""
+    """The bundle's variable (:func:`env_var`) with the bundle appended
+    (bundle wins conflicts)."""
     fragment = render(name)
     existing = (existing if existing is not None
-                else os.environ.get("XLA_FLAGS", ""))
+                else os.environ.get(env_var(name), ""))
     keep = [tok for tok in existing.split() if tok]
     # drop stale settings of the same flags so repeated applies stay idempotent
     names = {f"--{k}=" for k in bundle(name)}
@@ -99,13 +106,15 @@ def merged_flags(name: str, existing: str | None = None) -> str:
 
 
 def apply_bundle(name: str, *, env: dict | None = None) -> str:
-    """Merge the bundle into ``env['XLA_FLAGS']`` and return the new value."""
+    """Merge the bundle into ``env[env_var(name)]`` and return the new
+    value."""
     env = os.environ if env is None else env
-    merged = merged_flags(name, env.get("XLA_FLAGS"))
+    var = env_var(name)
+    merged = merged_flags(name, env.get(var))
     if env is os.environ and backend_initialized():
         warnings.warn(
             f"XLA flag bundle {name!r} applied after the JAX backend "
             "initialized; the current process keeps its old flags "
             "(subprocesses inherit the new ones)", stacklevel=2)
-    env["XLA_FLAGS"] = merged
+    env[var] = merged
     return merged

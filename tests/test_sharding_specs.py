@@ -14,12 +14,8 @@ from repro.train import sharding as shd
 
 
 def _abstract_mesh(shape, names):
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.sharding.AbstractMesh(
-            shape, names,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(names))
-    # pre-0.5 signature: tuple of (name, size) pairs
-    return jax.sharding.AbstractMesh(tuple(zip(names, shape)))
+    return jax.sharding.AbstractMesh(
+        shape, names, axis_types=(jax.sharding.AxisType.Auto,) * len(names))
 
 
 MESHES = [((16, 16), ("data", "model")),

@@ -177,6 +177,21 @@ def test_apply_bundle_sets_env():
         assert f"--{flag}={value}" in env["XLA_FLAGS"]
 
 
+@pytest.mark.parametrize("name", xla_flags.bundle_names())
+def test_bundle_goes_to_the_variable_its_compiler_reads(name):
+    """TPU flags are libtpu's (jaxlib aborts on them in XLA_FLAGS); CPU
+    flags are XLA's.  Each bundle leaves the other variable alone."""
+    var = "LIBTPU_INIT_ARGS" if name.startswith("tpu-") else "XLA_FLAGS"
+    other = ({"XLA_FLAGS", "LIBTPU_INIT_ARGS"} - {var}).pop()
+    env = {var: "--keep=me", other: "--untouched=1"}
+    xla_flags.apply_bundle(name, env=env)
+    assert xla_flags.env_var(name) == var
+    assert env[other] == "--untouched=1"
+    assert env[var].startswith("--keep=me ")
+    for flag, value in xla_flags.bundle(name).items():
+        assert f"--{flag}={value}" in env[var]
+
+
 def test_session_applies_bundle_option():
     from repro.api import Session
 
