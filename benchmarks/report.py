@@ -4,14 +4,6 @@ import json
 import os
 
 
-def fmt(x, digits=2):
-    if x is None:
-        return "-"
-    if isinstance(x, str):
-        return x
-    return f"{x:.{digits}e}"
-
-
 def gb(x):
     return "-" if x in (None, -1) else f"{x / 2**30:.2f}"
 
@@ -48,53 +40,8 @@ def dryrun_table(paths=DRYRUN_PATHS):
     return "\n".join(lines)
 
 
-def roofline_table(paths=("results/roofline.json",
-                          "results/roofline_mdp2.json",
-                          "results/roofline_whisper_opt.json",
-                          "results/roofline_mamba_opt.json")):
-    d = {}
-    for p in paths:
-        if not os.path.exists(p):
-            continue
-        tag = " (shipped-opt)" if p.endswith("_opt.json") else ""
-        for k, v in json.load(open(p)).items():
-            d[k + tag] = v
-    lines = ["| cell | compute s | memory s | collective s | dominant | "
-             "MODEL_FLOPs/dev | useful ratio | roofline frac |",
-             "|---|---|---|---|---|---|---|---|"]
-    for key, r in sorted(d.items()):
-        if r.get("status") != "ok":
-            lines.append(f"| {key} | FAIL {r.get('error', '')[:40]} | | | | | | |")
-            continue
-        lines.append(
-            f"| {key} | {fmt(r['compute_s'])} | {fmt(r['memory_s'])} | "
-            f"{fmt(r['collective_s'])} | **{r['dominant']}** | "
-            f"{fmt(r['model_flops_per_device'])} | "
-            f"{r['useful_flops_ratio']:.3f} | "
-            f"{r.get('roofline_fraction', 0):.2e} |")
-    return "\n".join(lines)
-
-
-def perf_table(path="results/perf_iters.jsonl"):
-    if not os.path.exists(path):
-        return "(no perf iterations recorded)"
-    rows = [json.loads(ln) for ln in open(path) if ln.strip()]
-    lines = ["| cell | variant | compute s | memory s | collective s | "
-             "bound (max term) | dominant |",
-             "|---|---|---|---|---|---|---|"]
-    for r in rows:
-        bound = max(r["compute_s"], r["memory_s"], r["collective_s"])
-        lines.append(
-            f"| {r['arch']}/{r['shape']} | {r['variant']} | "
-            f"{fmt(r['compute_s'])} | {fmt(r['memory_s'])} | "
-            f"{fmt(r['collective_s'])} | {fmt(bound)} | {r['dominant']} |")
-    return "\n".join(lines)
-
-
 if __name__ == "__main__":
     os.makedirs("results", exist_ok=True)
     with open("results/tables.md", "w") as f:
-        f.write("## Dry-run\n\n" + dryrun_table() + "\n\n")
-        f.write("## Roofline\n\n" + roofline_table() + "\n\n")
-        f.write("## Perf iterations\n\n" + perf_table() + "\n")
+        f.write("## Dry-run\n\n" + dryrun_table() + "\n")
     print("wrote results/tables.md")

@@ -23,7 +23,6 @@ Tables:
   adaptive      — -method auto vs fixed methods (within 1.3x of best) +
                   preconditioned-vs-plain GMRES on the outliers
   lm_substrate  — per-arch smoke train-step timing
-(roofline terms live in benchmarks/roofline.py -> results/roofline.json)
 """
 
 import argparse

@@ -30,6 +30,7 @@ import numpy as np
 
 from repro.core import driver as _driver
 from repro.core.ipi import IPIOptions
+from repro.utils import trace
 
 _TINY = 1e-30
 
@@ -85,8 +86,9 @@ def probe(mdp, opts: IPIOptions, *, probe_iters: int = 8, mesh=None,
     popts = dataclasses.replace(
         opts, method="vi", stop_criterion="probe",
         max_outer=min(k, opts.max_outer), pc_type="none", monitor=False)
-    r = _driver.solve(mdp, popts, mesh=mesh, layout=layout, v0=v0,
-                      chunk=popts.max_outer)
+    with trace.span(trace.PROBE):
+        r = _driver.solve(mdp, popts, mesh=mesh, layout=layout, v0=v0,
+                          chunk=popts.max_outer)
     res = float(r.residual)
     res0 = float(r.trace_residual[0]) if len(r.trace_residual) else res
     span = float(r.span)

@@ -46,6 +46,7 @@ from repro.core import driver
 from repro.core.driver import SolveResult
 from repro.core.mdp import DenseMDP, EllMDP, MatrixFreeMDP
 from repro.core.mdp import MDP as CoreMDP
+from repro.utils import trace
 from repro.utils.lru import LRUCache
 
 __all__ = ["Session", "madupite_session"]
@@ -254,6 +255,10 @@ class Session:
         traced predicate ``fn(m: repro.api.StopMetrics) -> bool`` compiled
         straight into the loop.
         """
+        with trace.counted_span(trace.SOLVE):
+            return self._solve(mdp, monitor, stop_criterion, overrides)
+
+    def _solve(self, mdp, monitor, stop_criterion, overrides) -> SolveResult:
         opts, mon_cb, mon_records = self._observe(overrides, monitor,
                                                   stop_criterion)
         mdp = self._wrap(mdp, opts)

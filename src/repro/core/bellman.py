@@ -33,6 +33,7 @@ from repro.core.comm import Axes
 from repro.core.mdp import (DenseMDP, EllMDP, MatrixFreeMDP, MDP,
                             batch_parts)
 from repro.kernels import matrix_free, ops
+from repro.utils import trace
 
 
 # --------------------------------------------------------------------------- #
@@ -366,8 +367,9 @@ def _p_pi_matvec(rows: PolicyRows, x_eff: jax.Array, axes: Axes,
         y = ops.ell_matvec(idx, rows.val, x_eff, impl=impl)
     else:
         dt = jnp.result_type(jnp.float32, rows.p.dtype, x_eff.dtype)
-        y = jnp.dot(rows.p.astype(dt), x_eff.astype(dt),
-                    precision=jax.lax.Precision.HIGHEST)
+        with trace.scope(trace.SPMV):
+            y = jnp.dot(rows.p.astype(dt), x_eff.astype(dt),
+                        precision=jax.lax.Precision.HIGHEST)
     return axes.psum_action(y)
 
 

@@ -29,6 +29,8 @@ from typing import Optional, Sequence, Union
 import jax
 import jax.numpy as jnp
 
+from repro.utils import trace
+
 
 AxisName = Union[str, Sequence[str], None]
 
@@ -43,6 +45,7 @@ class Axes:
     fleet: AxisName = dataclasses.field(default=None, metadata=dict(static=True))
 
     # ---- state-axis collectives -------------------------------------------------
+    @trace.scoped(trace.EXCHANGE)
     def allgather_state(self, x: jax.Array, dtype=None) -> jax.Array:
         """Gather the value vector across state shards (PETSc VecScatter
         analogue).  ``dtype`` optionally compresses the wire format (e.g.
@@ -54,6 +57,7 @@ class Axes:
             return x
         return jax.lax.all_gather(x, self.state, axis=0, tiled=True)
 
+    @trace.scoped(trace.EXCHANGE)
     def halo_exchange(self, x: jax.Array, halo: int, dtype=None) -> jax.Array:
         """Exchange ``halo`` boundary entries with ring neighbours instead of
         all-gathering the full vector — the TPU analogue of PETSc's

@@ -50,6 +50,7 @@ from repro.core.ipi import IPIOptions, SolveState
 from repro.core.mdp import (DenseMDP, EllMDP, MatrixFreeMDP, MDP, gammas_of,
                             stack_mdps)
 from repro.utils import checkpoint as ckpt
+from repro.utils import trace
 
 
 @dataclasses.dataclass
@@ -78,12 +79,21 @@ class SolveResult:
                 f"gap<= {self.gap_bound:.3e}{flag}")
 
 
+def _get(x):
+    """``x`` on the host.  A fetch of device arrays is one blocking host
+    transfer, counted in :func:`repro.utils.trace.host_transfers`."""
+    if any(isinstance(a, jax.Array) for a in jax.tree_util.tree_leaves(x)):
+        trace.count_host_transfer()
+    return jax.device_get(x)
+
+
 def _result(state: SolveState, opts: IPIOptions, gamma: float,
             n_orig: int) -> SolveResult:
-    k = int(state.k)
-    res = float(state.res)
-    converged = bool(state.done)  # the compiled stop criterion's verdict
-    v = np.asarray(jax.device_get(state.v))[:n_orig]
+    k = int(_get(state.k))
+    res = float(_get(state.res))
+    converged = bool(_get(state.done))  # the compiled stop criterion's verdict
+    span = float(_get(state.span))
+    v = np.asarray(_get(state.v))[:n_orig]
     gap = res / (1.0 - gamma)
     if converged and opts.stop_criterion == "span" and gamma < 1.0:
         # Midpoint correction (Puterman §6.6): for any v with
@@ -94,23 +104,23 @@ def _result(state: SolveState, opts: IPIOptions, gamma: float,
         # gamma * sp(d) / (2 * (1-gamma)) — the whole point of span
         # stopping, which the raw iterate (error only <= res/(1-gamma))
         # would squander.  A constant shift, so the policy is untouched.
-        tv = np.asarray(jax.device_get(state.tv))[:n_orig]
+        tv = np.asarray(_get(state.tv))[:n_orig]
         d = tv - v
         scale = gamma / (1.0 - gamma)
         v = tv + scale * (float(d.max()) + float(d.min())) / 2.0
-        gap = scale * float(state.span) / 2.0
+        gap = scale * span / 2.0
     return SolveResult(
         v=v,
-        policy=np.asarray(jax.device_get(state.pi))[:n_orig],
+        policy=np.asarray(_get(state.pi))[:n_orig],
         residual=res,
         gap_bound=gap,
         converged=converged,
         outer_iterations=k,
-        inner_iterations=int(state.inner_total),
-        trace_residual=np.asarray(state.trace_res)[:k + 1],
-        trace_inner=np.asarray(state.trace_inner)[:k],
-        diverged=bool(state.diverged),
-        span=float(state.span))
+        inner_iterations=int(_get(state.inner_total)),
+        trace_residual=np.asarray(_get(state.trace_res))[:k + 1],
+        trace_inner=np.asarray(_get(state.trace_inner))[:k],
+        diverged=bool(_get(state.diverged)),
+        span=span)
 
 
 def _validate_banded(mdp, halo: int, mesh, layout: str) -> None:
@@ -200,10 +210,10 @@ def _drain_monitor(mid: int, state: SolveState, done_prev, k_prev) -> None:
     ``jax.debug.callback`` host sync per outer iteration (``elapsed`` is the
     drain time).  ``done_prev`` / ``k_prev`` are the pre-chunk done mask and
     iteration counts (``done_prev=None`` for a single-instance solve)."""
-    k = np.asarray(jax.device_get(state.k))
-    tr = np.asarray(jax.device_get(state.trace_res))
-    ti = np.asarray(jax.device_get(state.trace_inner))
-    div_f = np.asarray(jax.device_get(state.diverged))
+    k = np.asarray(_get(state.k))
+    tr = np.asarray(_get(state.trace_res))
+    ti = np.asarray(_get(state.trace_inner))
+    div_f = np.asarray(_get(state.diverged))
     if k.ndim == 0:
         for kk in range(int(k_prev) + 1, int(k) + 1):
             # diverged flips exactly at the iteration the loop stopped on,
@@ -216,7 +226,7 @@ def _drain_monitor(mid: int, state: SolveState, done_prev, k_prev) -> None:
     act_prev = ~np.asarray(done_prev)
     if not act_prev.any():
         return
-    res_f = np.asarray(jax.device_get(state.res))
+    res_f = np.asarray(_get(state.res))
     # lockstep invariant: all active lanes share one outer index, so the
     # stream's per-iteration k_col sequence is exactly this range
     k_lo = int(np.asarray(k_prev)[act_prev].max())
@@ -339,7 +349,7 @@ def _trim_ckpt_state(state: SolveState, n_orig: int,
     fleet dim to the true ``b_orig``).  Restore re-pads for the resuming
     mesh, so a job may restart on a mesh that pads differently (elastic
     restart across device counts / fleet-axis sizes)."""
-    host = jax.device_get(state)
+    host = _get(state)
     lead = (lambda x: np.asarray(x)[:b_orig]) if b_orig is not None \
         else np.asarray
     return SolveState(
@@ -477,12 +487,13 @@ def solve(mdp: MDP, opts: IPIOptions = IPIOptions(), *,
     run_chunk, init = _make_runners(dev_mdp, opts, mesh, axes, None,
                                     n_true=n_orig)
 
-    state = _restore_or_init(init, v0, checkpoint_dir, verbose,
-                             expect=dict(n=n_orig))
+    with trace.span(trace.DRIVER_INIT):
+        state = _restore_or_init(init, v0, checkpoint_dir, verbose,
+                                 expect=dict(n=n_orig))
     save_each = bool(checkpoint_dir) and checkpoint_mode == "chunk"
 
     def save_state() -> None:
-        ckpt.save(checkpoint_dir, int(jax.device_get(state.k)),
+        ckpt.save(checkpoint_dir, int(_get(state.k)),
                   _trim_ckpt_state(state, n_orig, None),
                   meta=dict(method=opts.method, n=n_orig))
 
@@ -491,15 +502,16 @@ def solve(mdp: MDP, opts: IPIOptions = IPIOptions(), *,
         mid = methods.monitor_handle(monitor or methods.print_monitor)
     try:
         if mid:   # the k=0 (or resume-point) record, emitted host-side
-            k0, res0 = jax.device_get((state.k, state.res))
+            k0, res0 = _get((state.k, state.res))
             methods.emit_host(mid, int(k0), float(res0), 0)
         prev = None
         while True:
             # one host round-trip for the whole control tuple: separate
             # device_gets multiply the per-chunk sync latency,
             # which dominates warm small-n solves
-            k, res, done, div = jax.device_get(
-                (state.k, state.res, state.done, state.diverged))
+            with trace.span(trace.DRIVER_SYNC):
+                k, res, done, div = _get(
+                    (state.k, state.res, state.done, state.diverged))
             k, res, done, div = int(k), float(res), bool(done), bool(div)
             if verbose:
                 print(f"[driver] k={k} residual={res:.3e}"
@@ -522,7 +534,8 @@ def solve(mdp: MDP, opts: IPIOptions = IPIOptions(), *,
                 break
             prev = (k, res)
             k_hi = jnp.int32(min(k + chunk, opts.max_outer))
-            state = run_chunk(dev_mdp, state, k_hi, jnp.int32(mid))
+            with trace.span(trace.DRIVER_DISPATCH):
+                state = run_chunk(dev_mdp, state, k_hi, jnp.int32(mid))
             if mid and opts.monitor_mode == "chunk":
                 _drain_monitor(mid, state, None, k)
             if save_each:
@@ -532,10 +545,11 @@ def solve(mdp: MDP, opts: IPIOptions = IPIOptions(), *,
             jax.effects_barrier()   # flush in-flight monitor callbacks
             methods.monitor_release(mid)
 
-    if mesh is not None:
-        # gather the sharded fields for the host-side result
-        state = jax.device_get(state)
-    return _result(state, opts, mdp.gamma, n_orig)
+    with trace.span(trace.DRIVER_READBACK):
+        if mesh is not None:
+            # gather the sharded fields for the host-side result
+            state = _get(state)
+        return _result(state, opts, mdp.gamma, n_orig)
 
 
 def solve_many(mdps: Sequence[MDP] | MDP, opts: IPIOptions = IPIOptions(), *,
@@ -640,8 +654,9 @@ def solve_many(mdps: Sequence[MDP] | MDP, opts: IPIOptions = IPIOptions(), *,
     run_chunk, init = _make_runners(dev_mdp, opts, mesh, axes,
                                     dev_mdp.batch, n_true=nt_vec)
 
-    state = _restore_or_init(init, v0, checkpoint_dir, verbose,
-                             expect=dict(n=n_true, batch=b_orig))
+    with trace.span(trace.DRIVER_INIT):
+        state = _restore_or_init(init, v0, checkpoint_dir, verbose,
+                                 expect=dict(n=n_true, batch=b_orig))
     mid = 0
     if opts.monitor:
         # trim=b_orig: monitor records carry the TRUE fleet rows, not the
@@ -650,13 +665,14 @@ def solve_many(mdps: Sequence[MDP] | MDP, opts: IPIOptions = IPIOptions(), *,
                                      trim=b_orig)
     try:
         if mid:
-            k0, res0 = jax.device_get((state.k, state.res))
+            k0, res0 = _get((state.k, state.res))
             methods.emit_host(mid, np.asarray(k0), np.asarray(res0),
                               np.zeros(dev_mdp.batch, np.int32))
         while True:
             # one host round-trip per chunk (see the solve() loop)
-            k, res, crit, div = (np.asarray(x) for x in jax.device_get(
-                (state.k, state.res, state.done, state.diverged)))
+            with trace.span(trace.DRIVER_SYNC):
+                k, res, crit, div = (np.asarray(x) for x in _get(
+                    (state.k, state.res, state.done, state.diverged)))
             # isnan / diverged: a broken-down lane is not device-active ->
             # count it done (its result reports diverged, not converged)
             done = crit | (k >= opts.max_outer) | np.isnan(res) | div
@@ -668,7 +684,8 @@ def solve_many(mdps: Sequence[MDP] | MDP, opts: IPIOptions = IPIOptions(), *,
                 break
             k_hi = jnp.int32(min(int(k[~done].min()) + chunk,
                                  opts.max_outer))
-            state = run_chunk(dev_mdp, state, k_hi, jnp.int32(mid))
+            with trace.span(trace.DRIVER_DISPATCH):
+                state = run_chunk(dev_mdp, state, k_hi, jnp.int32(mid))
             if mid and opts.monitor_mode == "chunk":
                 _drain_monitor(mid, state, done, k)
             if checkpoint_dir:
@@ -682,9 +699,10 @@ def solve_many(mdps: Sequence[MDP] | MDP, opts: IPIOptions = IPIOptions(), *,
             jax.effects_barrier()   # flush in-flight monitor callbacks
             methods.monitor_release(mid)
 
-    state = jax.device_get(state)
-    out = []
-    for b in range(b_orig):
-        sb = jax.tree_util.tree_map(lambda x: np.asarray(x)[b], state)
-        out.append(_result(sb, opts, gammas[b], n_origs[b]))
+    with trace.span(trace.DRIVER_READBACK):
+        state = _get(state)
+        out = []
+        for b in range(b_orig):
+            sb = jax.tree_util.tree_map(lambda x: np.asarray(x)[b], state)
+            out.append(_result(sb, opts, gammas[b], n_origs[b]))
     return out

@@ -72,6 +72,7 @@ import jax.numpy as jnp
 from repro.core import bellman, methods, solvers
 from repro.core.comm import Axes
 from repro.core.mdp import MDP, batch_parts
+from repro.utils import trace
 
 # Back-compat view of the builtin method zoo.  The zoo itself is a LIVE
 # registry (repro.core.methods / repro.api.register_ksp): user-registered
@@ -380,6 +381,7 @@ def _span_of(d: jax.Array, axes: Axes, opts: IPIOptions,
     return dmax - dmin
 
 
+@trace.scoped(trace.OUTER)
 def _outer_core(mdp: MDP, state: SolveState, opts: IPIOptions,
                 axes: Axes, gamma_t: jax.Array | None):
     """One outer iteration minus the k/trace bookkeeping.

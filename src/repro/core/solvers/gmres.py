@@ -44,6 +44,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.comm import Axes
+from repro.utils import trace
 
 _TINY = 1e-30
 
@@ -89,6 +90,7 @@ def _det_backsolve(R, g):
     return jax.lax.fori_loop(0, n, step, jnp.zeros_like(g))
 
 
+@trace.scoped(trace.GMRES_CYCLE)
 def _arnoldi_cycle(matvec, b, x, *, restart: int, tol, axes: Axes,
                    deterministic: bool = False, precond=None):
     """One restart cycle. Returns (x_new, resnorm, iters_done)."""
@@ -96,8 +98,9 @@ def _arnoldi_cycle(matvec, b, x, *, restart: int, tol, axes: Axes,
     dt = x.dtype
     M = precond if precond is not None else (lambda v: v)
     norm2 = (lambda v: _det_norm2(axes, v)) if deterministic else axes.norm2
-    r = b - matvec(x)
-    beta = norm2(r)
+    with trace.scope(trace.GMRES_RESIDUAL):
+        r = b - matvec(x)
+        beta = norm2(r)
     v0 = r / jnp.where(beta > _TINY, beta, 1.0)
 
     V = jnp.zeros((restart + 1, n_local), dt).at[0].set(v0)
@@ -191,7 +194,8 @@ def _arnoldi_cycle(matvec, b, x, *, restart: int, tol, axes: Axes,
         # step.  Measure honestly; the next cycle restarts from the true
         # residual anyway, so this self-corrects at one matvec per cycle.
         # The plain path keeps the estimate (bit-identical to no-precond).
-        res = norm2(b - matvec(x_new))
+        with trace.scope(trace.GMRES_RESIDUAL):
+            res = norm2(b - matvec(x_new))
     return x_new, res, iters
 
 
@@ -217,8 +221,9 @@ def gmres(matvec, b: jax.Array, x0: jax.Array, *, tol, maxiter: int,
             deterministic=deterministic, precond=precond)
         return x, res, it + done_iters
 
-    r0 = b - matvec(x0)
-    res0 = _det_norm2(axes, r0) if deterministic else axes.norm2(r0)
+    with trace.scope(trace.GMRES_RESIDUAL):
+        r0 = b - matvec(x0)
+        res0 = _det_norm2(axes, r0) if deterministic else axes.norm2(r0)
 
     def cond(s):
         _, res, it = s

@@ -38,6 +38,8 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
+from repro.utils import trace
+
 from . import ops, ref
 
 __all__ = ["RowSpec", "build_rows_block", "mf_backup", "mf_policy_rows",
@@ -155,6 +157,7 @@ def _chunk_rows(spec, n_rows: int, acts: tuple, v, block_rows) -> int:
                                  v.shape[-1], v.dtype)
 
 
+@trace.scoped(trace.BACKUP)
 def mf_backup(spec, row0, n_rows: int, acts: tuple, gamma, v, *,
               mode: str = "mincost", idx_map=None, impl: str | None = None,
               block_rows: int | None = None):

@@ -42,6 +42,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.utils import trace
+
 from . import ref, tuning
 
 _DEFAULT_IMPL = "auto"
@@ -187,6 +189,7 @@ def _ell_backup(idx, val, cost, gamma, v, impl, block_rows):
 
 
 @functools.partial(jax.jit, static_argnames=("impl", "block_rows"))
+@trace.scoped(trace.BACKUP)
 def ell_backup(idx, val, cost, gamma, v, *, impl: str | None = None,
                block_rows: int | None = None):
     """Fused Bellman backup on an ELL block -> (v_new (n,), argmin (n,) int32)."""
@@ -243,6 +246,7 @@ def _ell_qvalues(idx, val, cost, gamma, v, impl, block_rows):
 
 
 @functools.partial(jax.jit, static_argnames=("impl", "block_rows"))
+@trace.scoped(trace.BACKUP)
 def ell_qvalues(idx, val, cost, gamma, v, *, impl: str | None = None,
                 block_rows: int | None = None):
     impl = resolve_impl(impl)
@@ -270,6 +274,7 @@ def _ell_matvec(idx, val, x, impl, block_rows):
 
 
 @functools.partial(jax.jit, static_argnames=("impl", "block_rows"))
+@trace.scoped(trace.SPMV)
 def ell_matvec(idx, val, x, *, impl: str | None = None,
                block_rows: int | None = None):
     """Policy-restricted SpMV y = P_pi @ x on (n, K) ELL rows."""
@@ -294,6 +299,7 @@ def _dense_backup(p, cost, gamma, v, impl):
 
 
 @functools.partial(jax.jit, static_argnames=("impl",))
+@trace.scoped(trace.BACKUP)
 def dense_backup(p, cost, gamma, v, *, impl: str | None = None):
     impl = resolve_impl(impl)
     if p.ndim == 4:

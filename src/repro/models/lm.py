@@ -175,9 +175,8 @@ class DecoderLM:
             fn = jax.checkpoint(fn, policy=policy, static_argnums=())
 
         if unroll:
-            # Python-loop execution (roofline analysis path: XLA cost_analysis
-            # counts while-loop bodies once, so the reduced-depth roofline
-            # lowers use this to get loop-free HLO; see benchmarks/roofline.py)
+            # Python-loop execution: loop-free HLO (XLA cost_analysis counts
+            # while-loop bodies once)
             l = jax.tree.leaves(params_stack)[0].shape[0]
             aux = zero_aux()
             caches = []

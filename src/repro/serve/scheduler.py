@@ -196,7 +196,8 @@ class Scheduler:
             mdps, monitor=self._demux(batch), **overrides)
         for req, res in zip(batch, results):
             req._complete(res)
-            self._telemetry.on_complete(req.latency)
+            self._telemetry.on_complete(req.dispatched - req.submitted,
+                                        req.completed - req.dispatched)
 
     def _demux(self, batch: list[Request]):
         """Per-bucket monitor callback forwarding each lane's row of the

@@ -3,6 +3,8 @@
 One :class:`Telemetry` instance per server.  Client threads bump the
 submit/reject counters, the scheduler thread the dispatch/completion ones;
 ``snapshot()`` renders the consistent dict ``Server.stats()`` returns.
+A completed request's latency splits into its queue wait (submit to
+dispatch) and its service (dispatch to completion).
 """
 
 from __future__ import annotations
@@ -37,6 +39,7 @@ class Telemetry:
         self.dispatched_requests = 0   # real requests across all dispatches
         self.padded_lanes = 0          # slot-padding duplicates solved
         self._batch_sizes: deque = deque(maxlen=_LATENCY_WINDOW)
+        # (queue wait, service) seconds of the latest completions
         self._latencies: deque = deque(maxlen=_LATENCY_WINDOW)
 
     # ---- recording ---------------------------------------------------------
@@ -55,10 +58,10 @@ class Telemetry:
             self.padded_lanes += n_padded
             self._batch_sizes.append(n_requests)
 
-    def on_complete(self, latency: float) -> None:
+    def on_complete(self, queue_s: float, service_s: float) -> None:
         with self._lock:
             self.completed += 1
-            self._latencies.append(latency)
+            self._latencies.append((queue_s, service_s))
 
     def on_fail(self, n: int = 1) -> None:
         with self._lock:
@@ -83,10 +86,14 @@ class Telemetry:
             "mean_size": (sum(sizes) / len(sizes)) if sizes else 0.0,
             "max_size": max(sizes) if sizes else 0,
         }
-        out["latency_s"] = {
-            "count": len(lat),
-            "mean": (sum(lat) / len(lat)) if lat else float("nan"),
-            "p50": percentile(lat, 50) if lat else float("nan"),
-            "p95": percentile(lat, 95) if lat else float("nan"),
-        }
+        out["latency_s"] = {"count": len(lat),
+                            **_summary([q + s for q, s in lat]),
+                            "queue": _summary([q for q, _ in lat]),
+                            "service": _summary([s for _, s in lat])}
         return out
+
+
+def _summary(xs: list) -> dict:
+    return {"mean": (sum(xs) / len(xs)) if xs else float("nan"),
+            "p50": percentile(xs, 50) if xs else float("nan"),
+            "p95": percentile(xs, 95) if xs else float("nan")}

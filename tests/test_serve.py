@@ -90,6 +90,23 @@ def test_concurrent_clients_bitwise_equal_and_coalesced():
     assert st["latency_s"]["p50"] > 0
 
 
+def test_latency_splits_into_queue_wait_and_service():
+    mdps = [_garnet(48, seed=40 + i) for i in range(4)]
+    with Server({**BASE, "-serve_batch_window": 0.1}) as srv:
+        reqs = _submit_all(srv, mdps)
+        for r in reqs:
+            r.result(timeout=600)
+        lat = srv.stats()["latency_s"]
+    for r in reqs:
+        assert r.submitted <= r.dispatched <= r.completed
+    assert lat["count"] == len(mdps)
+    assert lat["queue"]["p50"] > 0 and lat["service"]["p50"] > 0
+    assert lat["mean"] == pytest.approx(lat["queue"]["mean"]
+                                        + lat["service"]["mean"])
+    want = [r.latency for r in reqs]
+    assert lat["mean"] == pytest.approx(sum(want) / len(want))
+
+
 def test_two_shape_buckets_dispatch_separately():
     # 48 vs 96 states: pad waste past 25% -> bucket_indices splits, so one
     # coalesced group still dispatches as two compiled programs

@@ -15,6 +15,7 @@ compile written to it could not be read back without a chip.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -25,6 +26,7 @@ from repro.core import ipi
 from repro.core.comm import Axes
 from repro.core.mdp import EllMDP
 from repro.kernels import matrix_free, ops
+from repro.utils import trace
 
 GARNET = (1 << 20, 16, 8)
 STENCIL = (1 << 20, 4, 4)
@@ -97,10 +99,13 @@ def test_auto_spmv_fits_v5e(one_chip, no_persistent_cache, shape):
     assert _temp(c) <= 2 * n * k * 8
 
 
-def test_default_solve_chunk_fits_v5e(one_chip, no_persistent_cache):
+@pytest.fixture(scope="module")
+def default_solve_chunk(one_chip):
     """The whole compiled outer loop of the default method (ipi_gmres,
     float32) on the garnet table: the program phase (a) of chip_smoke.py
-    runs."""
+    runs, compiled once for the tests below."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
     n, m, k = GARNET
     idx, val, cost = _table(one_chip, n, m, k)
     mdp = EllMDP(idx=idx, val=val, cost=cost, gamma=0.99, n_global=n,
@@ -109,10 +114,38 @@ def test_default_solve_chunk_fits_v5e(one_chip, no_persistent_cache):
     axes = Axes()
     state = jax.eval_shape(lambda md: ipi.init_state(md, axes, opts), mdp)
     state = jax.tree.map(lambda a: _sds(one_chip, a.shape, a.dtype), state)
-    c = ipi.solve_chunk.lower(mdp, state, _sds(one_chip, (), jnp.int32),
-                              _sds(one_chip, (), jnp.int32), opts=opts,
-                              axes=axes).compile()
-    assert _temp(c) <= 2 * matrix_free.table_bytes(n, m, k)
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        return ipi.solve_chunk.lower(
+            mdp, state, _sds(one_chip, (), jnp.int32),
+            _sds(one_chip, (), jnp.int32), opts=opts, axes=axes).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", prev)
+        cc.reset_cache()
+
+
+def test_default_solve_chunk_fits_v5e(default_solve_chunk):
+    n, m, k = GARNET
+    assert _temp(default_solve_chunk) <= 2 * matrix_free.table_bytes(n, m, k)
+
+
+def test_table_gathers_keep_their_scopes_on_v5e(default_solve_chunk):
+    """The chip's compiler fuses each gather over the table into one
+    fusion whose ``op_name`` is its root's: the backup's gathers (n m K
+    values) keep ``repro.backup``, the SpMV's (n K values)
+    ``repro.spmv`` — the profile attributes the solve's time by them."""
+    n, m, k = GARNET
+    found = {}
+    for ln in default_solve_chunk.as_text().splitlines():
+        hit = re.match(rf"\s+(?:ROOT )?%\S+ = f32\[({n * m * k}|{n * k})\]"
+                       r"\S* fusion\(.*op_name=\"([^\"]*)\"", ln)
+        if hit:
+            scope = [c for c in hit.group(2).split("/")
+                     if c.startswith("repro.")][-1]
+            found.setdefault(int(hit.group(1)), set()).add(scope)
+    assert found == {n * m * k: {trace.BACKUP}, n * k: {trace.SPMV}}
 
 
 def test_pallas_on_tpu_raises_the_compilers_error(one_chip,
