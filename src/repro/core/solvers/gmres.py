@@ -60,6 +60,10 @@ def _det_norm2(axes: Axes, x):
     return jnp.sqrt(jnp.maximum(_det_dot(axes, x, x), 0.0))
 
 
+def _norm2(axes: Axes, deterministic: bool):
+    return (lambda v: _det_norm2(axes, v)) if deterministic else axes.norm2
+
+
 def _det_projections(axes: Axes, V, w):
     """The CGS2 projection vector ``V @ w`` computed one basis lane at a
     time (``lax.map``): every inner product is the same fixed-shape
@@ -90,17 +94,31 @@ def _det_backsolve(R, g):
     return jax.lax.fori_loop(0, n, step, jnp.zeros_like(g))
 
 
+def _residual(matvec, b, x, norm2):
+    """``r = b - A x`` and ``||r||_2``."""
+    with trace.scope(trace.GMRES_RESIDUAL):
+        r = b - matvec(x)
+        return r, norm2(r)
+
+
 @trace.scoped(trace.GMRES_CYCLE)
-def _arnoldi_cycle(matvec, b, x, *, restart: int, tol, axes: Axes,
-                   deterministic: bool = False, precond=None):
-    """One restart cycle. Returns (x_new, resnorm, iters_done)."""
+def _arnoldi_cycle(matvec, b, x, r=None, beta=None, run=True, *,
+                   restart: int, tol, axes: Axes, deterministic: bool = False,
+                   precond=None):
+    """One restart cycle from ``x``.  Returns (x_new, resnorm, iters_done).
+
+    ``r`` and ``beta`` are ``x``'s residual ``b - A x`` and its 2-norm where
+    the caller has them (the first cycle takes ``gmres``'s ``r0``);
+    otherwise the cycle measures them.  The Arnoldi loop stops at
+    convergence: steps after the residual estimate meets ``tol`` are not
+    executed, and none is where ``run`` is false.
+    """
     n_local = x.shape[0]
     dt = x.dtype
     M = precond if precond is not None else (lambda v: v)
-    norm2 = (lambda v: _det_norm2(axes, v)) if deterministic else axes.norm2
-    with trace.scope(trace.GMRES_RESIDUAL):
-        r = b - matvec(x)
-        beta = norm2(r)
+    norm2 = _norm2(axes, deterministic)
+    if r is None:
+        r, beta = _residual(matvec, b, x, norm2)
     v0 = r / jnp.where(beta > _TINY, beta, 1.0)
 
     V = jnp.zeros((restart + 1, n_local), dt).at[0].set(v0)
@@ -110,8 +128,12 @@ def _arnoldi_cycle(matvec, b, x, *, restart: int, tol, axes: Axes,
     g = jnp.zeros((restart + 1,), dt).at[0].set(beta)
     row_ids = jnp.arange(restart + 1)
 
-    def body(j, carry):
-        V, R, cs, sn, g, res, it, done = carry
+    def more(carry):
+        j, *_, done = carry
+        return (j < restart) & ~done
+
+    def body(carry):
+        j, V, R, cs, sn, g, _, _ = carry
         # right preconditioning: Krylov space of A M, solution mapped back
         # through M at cycle end -> the Givens residual estimate stays the
         # TRUE residual ||b - A x||, so forcing-term semantics are unchanged
@@ -154,26 +176,18 @@ def _arnoldi_cycle(matvec, b, x, *, restart: int, tol, axes: Axes,
         c_new = jnp.where(safe, hj / jnp.where(safe, denom, 1.0), 1.0)
         s_new = jnp.where(safe, hj1 / jnp.where(safe, denom, 1.0), 0.0)
         gj = jnp.take(g, j)
-        g_new = g.at[j + 1].set(-s_new * gj).at[j].set(c_new * gj)
-        res_new = jnp.abs(-s_new * gj)
+        g = g.at[j + 1].set(-s_new * gj).at[j].set(c_new * gj)
+        res = jnp.abs(-s_new * gj)
 
         # Column j of R: rotated h (positions < j already rotated; j -> denom;
         # the subdiagonal entry j+1 is annihilated by the new rotation).
         col = h.at[j].set(denom).at[j + 1].set(0.0)
-        R_new = R.at[:, j].set(col[:restart])
-        V_new = V.at[j + 1].set(v_next)
+        return (j + 1, V.at[j + 1].set(v_next), R.at[:, j].set(col[:restart]),
+                cs.at[j].set(c_new), sn.at[j].set(s_new), g, res, res <= tol)
 
-        keep = lambda new, old: jax.tree_util.tree_map(
-            lambda a, o: jnp.where(done, o, a), new, old)
-        V, R, cs_o, sn_o, g, res, it = keep(
-            (V_new, R_new, cs.at[j].set(c_new), sn.at[j].set(s_new), g_new,
-             res_new, it + 1),
-            (V, R, cs, sn, g, res, it))
-        done = done | (res <= tol)
-        return V, R, cs_o, sn_o, g, res, it, done
-
-    init = (V, R, cs, sn, g, beta, jnp.int32(0), beta <= tol)
-    V, R, _, _, g, res, iters, _ = jax.lax.fori_loop(0, restart, body, init)
+    init = (jnp.int32(0), V, R, cs, sn, g, beta,
+            (beta <= tol) | jnp.logical_not(run))
+    iters, V, R, _, _, g, res, _ = jax.lax.while_loop(more, body, init)
 
     # Solve the (iters x iters) triangular system; mask out unused columns.
     active = jnp.arange(restart) < iters
@@ -211,24 +225,31 @@ def gmres(matvec, b: jax.Array, x0: jax.Array, *, tol, maxiter: int,
     ``precond`` is an optional right preconditioner apply ``x -> M x``
     (``M ~= A^-1``, local shard in / local shard out).  ``None`` keeps the
     plain path bit-for-bit (the identity map adds no arithmetic).
+
+    The SpMVs it runs: ``r0``, which the first cycle starts from; one per
+    executed Arnoldi step (steps after convergence are not executed); the
+    residual each later cycle starts from; and with ``precond`` the true
+    residual each cycle ends with.
     """
     restart = int(restart)
 
-    def cycle(s):
-        x, _, it = s
+    def cycle(s, r=None, beta=None, run=True):
+        x, res, it = s
         x, res, done_iters = _arnoldi_cycle(
-            matvec, b, x, restart=restart, tol=tol, axes=axes,
+            matvec, b, x, r, beta, run, restart=restart, tol=tol, axes=axes,
             deterministic=deterministic, precond=precond)
         return x, res, it + done_iters
-
-    with trace.scope(trace.GMRES_RESIDUAL):
-        r0 = b - matvec(x0)
-        res0 = _det_norm2(axes, r0) if deterministic else axes.norm2(r0)
 
     def cond(s):
         _, res, it = s
         return (res > tol) & (it < maxiter)
 
-    x, res, iters = jax.lax.while_loop(
-        cond, cycle, (x0, res0, jnp.int32(0)))
+    r0, res0 = _residual(matvec, b, x0, _norm2(axes, deterministic))
+    s = (x0, res0, jnp.int32(0))
+    # The first cycle is traced apart from the loop, so that r0 reaches it
+    # without a residual vector riding in the loop's carry: on a TPU v5e a
+    # second vector there moved x out of VMEM, and the backup that reads x
+    # next ran 1.6x slower (PERF.md).
+    s = cycle(s, r0, res0, run=cond(s))
+    x, res, iters = jax.lax.while_loop(cond, cycle, s)
     return x, iters, res
