@@ -15,13 +15,17 @@ _PEAKS = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                       "peaks.json")
 
 
-def backup_bytes(n: int, m: int, k: int, *, idx_bytes: int = 4,
-                 val_bytes: int = 4, v_bytes: int = 4) -> int:
+def backup_bytes(n: int, m: int, k: int, *, values: int | None = None,
+                 idx_bytes: int = 4, val_bytes: int = 4,
+                 v_bytes: int = 4) -> int:
     """Fused Bellman backup ``(min_a Q, argmin_a Q)`` over an ``(n, m, k)``
     ELL table: ``idx`` and ``val`` (n m k) and ``cost`` (n m) read once,
-    ``v`` (n) read once, ``Tv`` and the int32 argmin (n each) written."""
+    the ``values`` entries of ``v`` it gathers from read once (``n``,
+    unless the rows are one chip's shard and gather from the whole
+    exchanged vector), ``Tv`` and the int32 argmin (n each) written."""
+    values = n if values is None else values
     return (n * m * k * (idx_bytes + val_bytes) + n * m * val_bytes
-            + n * v_bytes + n * v_bytes + n * 4)
+            + values * v_bytes + n * v_bytes + n * 4)
 
 
 def backup_flops(n: int, m: int, k: int) -> int:
@@ -30,11 +34,21 @@ def backup_flops(n: int, m: int, k: int) -> int:
     return 2 * n * m * k + 3 * n * m
 
 
-def spmv_bytes(n: int, k: int, *, idx_bytes: int = 4, val_bytes: int = 4,
+def spmv_bytes(n: int, k: int, *, values: int | None = None,
+               idx_bytes: int = 4, val_bytes: int = 4,
                x_bytes: int = 4) -> int:
     """Policy-restricted SpMV ``y = P_pi x`` over ``(n, k)`` rows:
-    ``idx`` and ``val`` read once, ``x`` read once, ``y`` written."""
-    return n * k * (idx_bytes + val_bytes) + n * x_bytes + n * x_bytes
+    ``idx`` and ``val`` read once, the ``values`` entries of ``x`` read
+    once (``n`` unless the rows are a shard), ``y`` written."""
+    values = n if values is None else values
+    return n * k * (idx_bytes + val_bytes) + values * x_bytes + n * x_bytes
+
+
+def allgather_bytes(n: int, chips: int) -> int:
+    """Least bytes one chip receives in a tiled all-gather of an ``n``
+    float32 vector split evenly over ``chips``: every entry it does not
+    hold."""
+    return (n - n // chips) * 4
 
 
 def spmv_flops(n: int, k: int) -> int:
@@ -52,12 +66,16 @@ def peaks(device_kind: str) -> dict:
     return table[device_kind]
 
 
-def roofline_share(flops: int, nbytes: int, seconds: float,
-                   peak: dict) -> tuple[float, str]:
+def roofline_share(flops: int, nbytes: int, seconds: float, peak: dict,
+                   link: str = "hbm") -> tuple[float, str]:
     """``(percent, bound)``: the least time the chip could take, the larger
-    of operations over peak FLOP/s and bytes over peak bandwidth, as a
-    share of ``seconds``; ``bound`` names which of the two it was."""
+    of operations over peak FLOP/s and bytes over the peak bandwidth of
+    ``link`` (``"hbm"``, or ``"ici"``: the interconnect, all of a chip's
+    links), as a share of ``seconds``; ``bound`` names which of the two
+    it was."""
     t_flops = flops / peak["flops_per_s"]
-    t_bytes = nbytes / peak["hbm_bytes_per_s"]
-    bound = "hbm" if t_bytes >= t_flops else "flops"
+    per_s = peak["hbm_bytes_per_s"] if link == "hbm" \
+        else peak["ici_bits_per_s"] / 8
+    t_bytes = nbytes / per_s
+    bound = link if t_bytes >= t_flops else "flops"
     return 100.0 * max(t_flops, t_bytes) / seconds, bound

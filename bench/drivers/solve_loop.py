@@ -11,7 +11,10 @@ then calls ``Session.solve`` on the same instance until ``--seconds`` have
 passed (no solve starts after that); each solve ends when its result is on
 the host.
 
-A traced run traces one more warm solve instead, then times the kernels
+A traced run traces one more warm solve instead.  It records the least
+bytes and operations of one call of each kernel on one chip's rows (a
+shard's rows gather from the whole exchanged vector) and, on a mesh, of
+one all-gather of the values.  On one chip it then times the kernels
 alone on the cell's own table: ``ops.ell_backup`` and ``ops.ell_matvec``
 through the dispatch point, each under a module name of its own.
 """
@@ -35,17 +38,29 @@ def _options(cfg: dict) -> dict:
             "-verbose": False}
 
 
-def _kernel_calls(ctx, table, gamma, v, pi) -> dict:
-    """Warm, then (inside the caller's trace) run each kernel alone;
-    returns the facts the roofline readers need."""
+def _kernel_facts(rows: int, values: int, m: int, k: int) -> dict:
+    """Operations and least bytes of one call of each kernel over ``rows``
+    table rows that gather from a vector of ``values`` entries."""
+    from bench import counts
+
+    return {
+        "backup": {"flops": counts.backup_flops(rows, m, k),
+                   "bytes": counts.backup_bytes(rows, m, k, values=values)},
+        "spmv": {"flops": counts.spmv_flops(rows, k),
+                 "bytes": counts.spmv_bytes(rows, k, values=values)},
+    }
+
+
+def _kernel_calls(ctx, table, gamma, v, pi):
+    """Warm each kernel; returns the call that (inside the caller's
+    trace) runs each ``KERNEL_REPEATS`` times alone, under the module
+    names ``bench_backup`` and ``bench_spmv``."""
     import jax
     import jax.numpy as jnp
 
-    from bench import counts
     from repro.kernels import ops
 
     idx, val, cost = table
-    n, m, k = idx.shape
 
     @jax.jit
     def policy_rows(idx, val, pi):
@@ -74,15 +89,7 @@ def _kernel_calls(ctx, table, gamma, v, pi) -> dict:
             for _ in range(KERNEL_REPEATS):
                 jax.block_until_ready(spmv(idx_pi, val_pi, vj))
 
-    facts = {
-        "backup": {"module": "bench_backup", "calls": KERNEL_REPEATS,
-                   "flops": counts.backup_flops(n, m, k),
-                   "bytes": counts.backup_bytes(n, m, k)},
-        "spmv": {"module": "bench_spmv", "calls": KERNEL_REPEATS,
-                 "flops": counts.spmv_flops(n, k),
-                 "bytes": counts.spmv_bytes(n, k)},
-    }
-    return facts, timed
+    return timed
 
 
 def run(ctx) -> Outcome:
@@ -111,17 +118,24 @@ def run(ctx) -> Outcome:
         warm = sess.solve(mdp)
     ctx.log("warmup", solve_s=time.monotonic() - t0,
             outer=warm.outer_iterations, inner=warm.inner_iterations,
-            residual=warm.residual, converged=warm.converged)
+            residual=warm.residual, converged=warm.converged,
+            peak_bytes=peak_bytes(ctx.devices))
     setup_compile = ctx.clock.delta(ctx.clock.read(), clock0)
     facts = {"setup_compile_s": setup_compile["seconds"],
              "build_s": build_s, "n_devices": len(ctx.devices)}
     kernels = None
-    if ctx.trace and mesh is None:
+    if ctx.trace:
         from bench import counts
 
+        chips = 1 if mesh is None else mesh.size
         facts["peak"] = counts.peaks(ctx.devices[0].device_kind)
-        facts["kernels"], kernels = _kernel_calls(ctx, table, gamma,
-                                                  warm.v, warm.policy)
+        facts["kernels"] = _kernel_facts(n // chips, n, m, k)
+        if mesh is None:
+            kernels = _kernel_calls(ctx, table, gamma, warm.v, warm.policy)
+            for name, facts_k in facts["kernels"].items():
+                facts_k.update(module="bench_" + name, calls=KERNEL_REPEATS)
+        else:
+            facts["exchange"] = {"bytes": counts.allgather_bytes(n, chips)}
     setup_s = time.monotonic() - ctx.t_start
     ctx.log("setup", setup_s=setup_s, compile_s=setup_compile["seconds"],
             programs=setup_compile["programs"],

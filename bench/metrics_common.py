@@ -9,10 +9,10 @@ from bench import trace as tr
 def kernel_roofline(facts: dict, kernel: str):
     """Percent of the roofline one call of ``kernel`` reached, from the
     device time of its named module in the trace; None when the run timed
-    no such call."""
+    no such call (a run on a mesh times none)."""
     k = (facts.get("kernels") or {}).get(kernel)
     trace = facts.get("trace")
-    if k is None or trace is None:
+    if k is None or trace is None or "module" not in k:
         return None
     per_dev = tr.module_ns(trace, k["module"])
     if not per_dev:

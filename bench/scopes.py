@@ -45,6 +45,7 @@ from bench import trace as tr
 PROGRAM_PREFIX = "repro."
 SPMV = "repro.spmv"
 BACKUP = "repro.backup"
+EXCHANGE = "repro.exchange"
 SOLVE_SPAN = "repro.solve"
 HOST_WAITS = ("repro.driver.sync", "repro.driver.readback")
 TRANSFERS_STAT = "host_transfers"
@@ -310,3 +311,62 @@ def solve_roofline(facts: dict, kernel: str, scope: str):
     share, _ = counts.roofline_share(k["flops"] * n[d], k["bytes"] * n[d],
                                      per[d][scope] / 1e9, facts["peak"])
     return share
+
+
+def _allgathers(p: Profile, d, lo: int, hi: int) -> tuple[int, list]:
+    """``(calls, events)`` of the all-gathers under ``repro.exchange`` that
+    started on device ``d`` in ``[lo, hi)``: a call is an all-gather
+    operation or the start of an async one; the events cover each from
+    its start to its done."""
+    def mine(evs):
+        return [e for e in evs if e.scope == EXCHANGE
+                and "all-gather" in e.name and lo <= e.start < hi]
+    ops = mine(p.trace.ops.get(d, []))
+    calls = sum("done" not in e.name for e in ops)
+    return calls, ops + mine(p.trace.async_ops.get(d, []))
+
+
+def exchange_roofline(facts: dict):
+    """Percent of the interconnect's peak that the solve's all-gathers of
+    the values reached on the device that ran the most: the least bytes
+    one chip receives per all-gather times the calls, over the peak, over
+    their device time (as ``trace.exposed_collective_ns`` counts it);
+    None when the run has no such facts or no all-gather."""
+    from bench import counts
+
+    ex = facts.get("exchange")
+    p = from_facts(facts)
+    if ex is None or p is None or "peak" not in facts:
+        return None
+    lo, hi = window(p, facts)
+    per = {d: _allgathers(p, d, lo, hi) for d in p.trace.ops}
+    d = max(per, key=lambda dev: (per[dev][0], dev), default=None)
+    if d is None or per[d][0] == 0:
+        return None
+    calls, evs = per[d]
+    share, _ = counts.roofline_share(0, ex["bytes"] * calls,
+                                     tr.covered_ns(evs, lo, hi) / 1e9,
+                                     facts["peak"], link="ici")
+    return share
+
+
+def exposed_share(facts: dict, scope: str):
+    """Percent of the traced window in which a collective of ``scope`` ran
+    on a device (an operation, or an async one from its start to its done)
+    and no other operation ran there, the mean over devices; None when no
+    such collective ran."""
+    p = from_facts(facts)
+    if p is None:
+        return None
+    lo, hi = window(p, facts)
+
+    def mine(e):
+        return e.scope == scope
+
+    if not any(mine(e) and tr.is_collective(e.name)
+               and e.end > lo and e.start < hi
+               for line in (p.trace.ops, p.trace.async_ops)
+               for evs in line.values() for e in evs):
+        return None
+    exposed = tr.exposed_collective_ns(p.trace, lo, hi, counted=mine)
+    return 100.0 * sum(exposed.values()) / len(exposed) / (hi - lo)

@@ -44,6 +44,16 @@ def answer_altered(monkeypatch):
     monkeypatch.setattr(driver, "_result", altered)
 
 
+def exchange_left_out(monkeypatch):
+    """Each chip uses its own block of the values where it should have
+    the whole vector (its block repeated)."""
+    from repro.core.comm import Axes
+
+    monkeypatch.setattr(Axes, "allgather_state", lambda self, x, dtype=None: (
+        x if self.state is None
+        else jnp.concatenate([x] * self.state_size())))
+
+
 def control(monkeypatch):
     """The control in the program's place: every answer the solve returns
     is replaced by one bfloat16 backup of its values on the solved table

@@ -5,8 +5,9 @@ CPU at a small size, with the harness's look for a chip skipped, once for
 each fault the solve cells can have: a step that returns its state
 unchanged, an answer altered where it is produced, the lower-precision
 control in the program's place, and (on four virtual devices) the value
-exchange between chips left out.  A sound run of the same size reads
-``correct`` true.
+exchange between chips left out.  The four-chip cell's own configuration
+(``1d``) meets each of them on four virtual devices.  A sound run of the
+same size reads ``correct`` true.
 """
 
 import json
@@ -42,12 +43,11 @@ def test_fault_is_not_correct(fault, monkeypatch):
 _EXCHANGE = r"""
 import json, sys, time
 sys.path[:0] = [sys.argv[1], sys.argv[1] + "/src"]
-import jax, jax.numpy as jnp
-if sys.argv[2] == "broken":
-    from repro.core.comm import Axes
-    Axes.allgather_state = lambda self, x, dtype=None: (
-        x if self.state is None else jnp.concatenate([x] * self.state_size()))
+import pytest
 from bench import harness
+from bench.tests import faults
+if sys.argv[2] == "broken":
+    faults.exchange_left_out(pytest.MonkeyPatch())
 line = harness.run_cell("garnet_1m.solve", 3000000009, 1.0, False,
                         t_start=time.monotonic(), require_chip=False,
                         persistent_cache=False,
@@ -71,3 +71,74 @@ def test_exchange_left_out_is_not_correct(mode):
     line = json.loads(proc.stdout.strip().splitlines()[-1])
     assert line["device"]["count"] == 4
     assert line["correct"] is (mode == "sound"), line
+
+
+_FOUR_CHIP = r"""
+import json, sys, time
+sys.path[:0] = [sys.argv[1], sys.argv[1] + "/src"]
+import pytest
+from bench import counts, harness
+from bench.drivers import solve_loop
+from bench.tests import faults
+fault = sys.argv[2]
+if fault != "sound":
+    getattr(faults, fault)(pytest.MonkeyPatch())
+# the CPU is no device of the peak table: read the chip's entry instead
+real = counts.peaks
+counts.peaks = lambda kind: real("TPU v5 lite")
+facts = {}
+run = solve_loop.run
+def spy(ctx):
+    out = run(ctx)
+    facts.update(out.facts)
+    return out
+solve_loop.run = spy
+line = harness.run_cell("garnet_16m_x4.solve", 3000001597 + (1 << 32), 1.0,
+                        sys.argv[3] == "1", t_start=time.monotonic(),
+                        require_chip=False, persistent_cache=False,
+                        config_override={"n": 4096})
+print(json.dumps({"line": line, "kernels": facts.get("kernels"),
+                  "exchange": facts.get("exchange")}))
+"""
+
+
+def _four_chip(fault, traced):
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    proc = subprocess.run([sys.executable, "-c", _FOUR_CHIP, ROOT, fault,
+                           str(int(traced))],
+                          env=env, capture_output=True, text=True,
+                          timeout=600)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    return json.loads(proc.stdout.strip().splitlines()[-1])
+
+
+@pytest.mark.parametrize("fault", ["sound", "exchange_left_out",
+                                   "state_unchanged", "answer_altered",
+                                   "control"])
+def test_four_chip_cell_faults(fault):
+    """``garnet_16m_x4.solve`` at n = 4096 on four virtual CPU devices,
+    its own ``1d`` configuration: sound, it reads ``correct`` true; with
+    each fault planted under the timed path, false."""
+    got = _four_chip(fault, traced=False)
+    line = got["line"]
+    assert line["device"]["count"] == 4
+    assert line["correct"] is (fault == "sound"), line
+
+
+def test_four_chip_traced_run_records_the_shard_counts():
+    """A traced run on a mesh records what the in-solve rooflines and the
+    exchange's roofline read: one chip's rows gathering from the whole
+    vector, and the bytes one chip receives per all-gather."""
+    from bench import counts
+
+    got = _four_chip("sound", traced=True)
+    assert got["line"]["correct"] is True
+    n, m, k, rows = 4096, 16, 8, 1024
+    assert got["kernels"] == {
+        "backup": {"flops": counts.backup_flops(rows, m, k),
+                   "bytes": counts.backup_bytes(rows, m, k, values=n)},
+        "spmv": {"flops": counts.spmv_flops(rows, k),
+                 "bytes": counts.spmv_bytes(rows, k, values=n)}}
+    assert got["exchange"] == {"bytes": (n - rows) * 4}
+

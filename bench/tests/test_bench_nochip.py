@@ -32,7 +32,7 @@ def _no_result(proc):
         assert not (isinstance(obj, dict) and "metrics" in obj), line
 
 
-@pytest.mark.parametrize("workload", ["garnet_1m.solve"])
+@pytest.mark.parametrize("workload", ["garnet_1m.solve", "garnet_16m_x4.solve"])
 def test_refuses_the_cpu(workload):
     proc = _run(ROOT, workload)
     _no_result(proc)

@@ -156,6 +156,11 @@ def _length(intervals) -> int:
     return sum(b - a for a, b in intervals)
 
 
+def covered_ns(events, lo: int, hi: int) -> int:
+    """ns of ``[lo, hi)`` that at least one of ``events`` covers."""
+    return _length(_union(_clip(events, lo, hi)))
+
+
 def leaves(events: list) -> list:
     """Events that contain no other event of the same line (a loop or call
     op spans the operations it runs)."""
@@ -174,27 +179,29 @@ def leaves(events: list) -> list:
 
 def busy_ns(trace: Trace, lo: int, hi: int) -> dict:
     """Device id -> ns inside ``[lo, hi)`` in which some operation ran."""
-    return {d: _length(_union(_clip(evs, lo, hi)))
-            for d, evs in trace.ops.items()}
+    return {d: covered_ns(evs, lo, hi) for d, evs in trace.ops.items()}
 
 
 def is_collective(name: str) -> bool:
     return any(c in name for c in COLLECTIVES)
 
 
-def exposed_collective_ns(trace: Trace, lo: int, hi: int) -> dict:
+def exposed_collective_ns(trace: Trace, lo: int, hi: int,
+                          counted=None) -> dict:
     """Device id -> ns inside ``[lo, hi)`` in which a collective ran (as an
     operation, or between an async collective's start and done) while no
-    other (leaf) operation ran on that device."""
+    other (leaf) operation ran on that device.  ``counted(event)``, when
+    given, narrows the collectives counted; the others count as other
+    operations."""
+    def coll_(e):
+        return is_collective(e.name) and (counted is None or counted(e))
+
     out = {}
     for d, evs in trace.ops.items():
         lv = leaves(evs)
-        pending = [e for e in trace.async_ops.get(d, [])
-                   if is_collective(e.name)]
-        coll = _union(_clip([e for e in lv if is_collective(e.name)]
-                            + pending, lo, hi))
-        comp = _union(_clip([e for e in lv if not is_collective(e.name)],
-                            lo, hi))
+        pending = [e for e in trace.async_ops.get(d, []) if coll_(e)]
+        coll = _union(_clip([e for e in lv if coll_(e)] + pending, lo, hi))
+        comp = _union(_clip([e for e in lv if not coll_(e)], lo, hi))
         out[d] = _length(coll) - _overlap(coll, comp)
     return out
 
