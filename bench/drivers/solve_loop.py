@@ -1,9 +1,9 @@
 """Closed loop of one client: the cell's instance solved back to back.
 
-Set-up makes the configuration's GARNET instance (``instance_seed``) on the
-device with its states renamed by a permutation drawn from the run's seed,
-so that every seed gives the solver the same work (sharded over the chips
-under the ``1d`` layout), opens a
+Set-up makes the configuration's instance on the device through the
+family that its ``family`` names (``bench/families/<family>.py``, whose
+docstring says what the run's seed does to it), sharded over the chips
+under the ``1d`` layout, opens a
 ``repro.api.Session`` with the configuration's options, and runs one whole
 solve: ``IPIOptions`` are static, so the tolerance and the iteration caps
 are compiled in and only a whole solve warms every program.  The window
@@ -11,12 +11,12 @@ then calls ``Session.solve`` on the same instance until ``--seconds`` have
 passed (no solve starts after that); each solve ends when its result is on
 the host.
 
-A traced run traces one more warm solve instead.  It records the least
-bytes and operations of one call of each kernel on one chip's rows (a
-shard's rows gather from the whole exchanged vector) and, on a mesh, of
-one all-gather of the values.  On one chip it then times the kernels
-alone on the cell's own table: ``ops.ell_backup`` and ``ops.ell_matvec``
-through the dispatch point, each under a module name of its own.
+A traced run traces one more warm solve instead.  It records the family's
+kernel facts, the least bytes and operations of one call of each kernel on
+one chip's rows (a shard's rows gather from the whole exchanged vector)
+and, on a mesh, of one all-gather of the values.  On one chip it then
+times the family's kernels alone on the cell's own table, each under a
+module name of its own.
 """
 
 from __future__ import annotations
@@ -25,94 +25,43 @@ import time
 
 import numpy as np
 
-from bench import gen, reference
+from bench import families, reference
 from bench import trace as tr
 from bench.harness import TRACE_DIR, Outcome, peak_bytes
 
-KERNEL_REPEATS = 3
-
 
 def _options(cfg: dict) -> dict:
+    """The Session options: the configuration's method, precision,
+    tolerance and layout, with its ``options`` (extra Session options)
+    over them."""
     return {"-method": cfg["method"], "-dtype": cfg["dtype"],
             "-atol": cfg["atol"], "-layout": cfg["layout"],
-            "-verbose": False}
-
-
-def _kernel_facts(rows: int, values: int, m: int, k: int) -> dict:
-    """Operations and least bytes of one call of each kernel over ``rows``
-    table rows that gather from a vector of ``values`` entries."""
-    from bench import counts
-
-    return {
-        "backup": {"flops": counts.backup_flops(rows, m, k),
-                   "bytes": counts.backup_bytes(rows, m, k, values=values)},
-        "spmv": {"flops": counts.spmv_flops(rows, k),
-                 "bytes": counts.spmv_bytes(rows, k, values=values)},
-    }
-
-
-def _kernel_calls(ctx, table, gamma, v, pi):
-    """Warm each kernel; returns the call that (inside the caller's
-    trace) runs each ``KERNEL_REPEATS`` times alone, under the module
-    names ``bench_backup`` and ``bench_spmv``."""
-    import jax
-    import jax.numpy as jnp
-
-    from repro.kernels import ops
-
-    idx, val, cost = table
-
-    @jax.jit
-    def policy_rows(idx, val, pi):
-        sel = pi[:, None, None]
-        return (jnp.take_along_axis(idx, sel, axis=1)[:, 0],
-                jnp.take_along_axis(val, sel, axis=1)[:, 0])
-
-    def bench_backup(idx, val, cost, v):
-        with jax.named_scope("bench_backup"):
-            return ops.ell_backup(idx, val, cost, gamma, v)
-
-    def bench_spmv(idx_pi, val_pi, v):
-        with jax.named_scope("bench_spmv"):
-            return ops.ell_matvec(idx_pi, val_pi, v)
-
-    backup, spmv = jax.jit(bench_backup), jax.jit(bench_spmv)
-    vj = jnp.asarray(v, jnp.float32)
-    idx_pi, val_pi = policy_rows(idx, val, jnp.asarray(pi, jnp.int32))
-    jax.block_until_ready((backup(idx, val, cost, vj),
-                           spmv(idx_pi, val_pi, vj)))
-
-    def timed():
-        with ctx.span("kernels"):
-            for _ in range(KERNEL_REPEATS):
-                jax.block_until_ready(backup(idx, val, cost, vj))
-            for _ in range(KERNEL_REPEATS):
-                jax.block_until_ready(spmv(idx_pi, val_pi, vj))
-
-    return timed
+            "-verbose": False, **cfg.get("options", {})}
 
 
 def run(ctx) -> Outcome:
     import jax
 
-    from repro.api import MDP, Session
+    from repro.api import Session
 
     cfg = ctx.config
-    n, m, k, gamma = cfg["n"], cfg["m"], cfg["k"], cfg["gamma"]
+    if "family" not in cfg:
+        raise ValueError(f"configuration {cfg.get('name')!r} names no "
+                         f"family (bench/families/<family>.py)")
+    family = families.load(cfg["family"])
+    n = cfg["n"]
     clock0 = ctx.clock.read()
     sess = Session(_options(cfg))
     mesh, _ = sess.placement()
     t0 = time.monotonic()
     with ctx.span("build"):
-        table = gen.garnet(cfg["instance_seed"], n, m, k, relabel=ctx.seed,
-                           mesh=mesh)
+        table = family.table(cfg, ctx.seed, mesh)
         jax.block_until_ready(table)
     build_s = time.monotonic() - t0
-    ctx.log("build", n=n, m=m, k=k, gamma=gamma, build_s=build_s,
-            table_bytes=sum(a.nbytes for a in table),
+    ctx.log("build", family=family.name, n=n, gamma=cfg["gamma"],
+            build_s=build_s, table_bytes=sum(a.nbytes for a in table),
             peak_bytes=peak_bytes(ctx.devices))
-    mdp = MDP.from_arrays(idx=table[0], val=table[1], cost=table[2],
-                          gamma=gamma, validate=False)
+    mdp = family.mdp(cfg, table)
     with ctx.span("warmup"):
         t0 = time.monotonic()
         warm = sess.solve(mdp)
@@ -129,13 +78,16 @@ def run(ctx) -> Outcome:
 
         chips = 1 if mesh is None else mesh.size
         facts["peak"] = counts.peaks(ctx.devices[0].device_kind)
-        facts["kernels"] = _kernel_facts(n // chips, n, m, k)
+        facts["kernels"] = family.kernel_facts(cfg, table, n // chips, n)
         if mesh is None:
-            kernels = _kernel_calls(ctx, table, gamma, warm.v, warm.policy)
-            for name, facts_k in facts["kernels"].items():
-                facts_k.update(module="bench_" + name, calls=KERNEL_REPEATS)
+            kernels = family.kernel_calls(ctx, cfg, table, warm.v,
+                                          warm.policy)
         else:
             facts["exchange"] = {"bytes": counts.allgather_bytes(n, chips)}
+        if kernels is not None:
+            for name, facts_k in facts["kernels"].items():
+                facts_k.update(module="bench_" + name,
+                               calls=families.KERNEL_REPEATS)
     setup_s = time.monotonic() - ctx.t_start
     ctx.log("setup", setup_s=setup_s, compile_s=setup_compile["seconds"],
             programs=setup_compile["programs"],
@@ -184,11 +136,11 @@ def run(ctx) -> Outcome:
             else 0.0
         out.window_s = s.dur / 1e9
         out.breakdown = tr.breakdown(trace, s.start, s.end)
-    out.checks = check_answers(ctx, table, answers)
+    out.checks = check_answers(ctx, family, table, answers)
     return out
 
 
-def check_answers(ctx, table, answers) -> dict:
+def check_answers(ctx, family, table, answers) -> dict:
     """Every distinct answer of the window against the reference; the
     worst reading of each number beside its limit."""
     cfg = ctx.config
@@ -202,7 +154,7 @@ def check_answers(ctx, table, answers) -> dict:
             continue
         seen.add(key)
         if blocks is None:
-            blocks = list(reference.host_blocks(table))
+            blocks = list(family.host_blocks(cfg, table))
         got = reference.evaluate(blocks, cfg["gamma"], r.v, r.policy,
                                  cfg["n"])
         for name, value in got.items():

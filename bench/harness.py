@@ -4,9 +4,11 @@
 (``bench/configs/<config>.json``) and a traffic mix
 (``bench/traffic/<traffic>.json``).  The mix's ``kind`` names the driver,
 ``bench/drivers/<kind>.py``, which builds the cell's data from the seed,
-warms up, measures, and checks its answers against the reference.  Each
-per-layer metric is read by ``bench/metrics/<metric>.py`` from the facts
-the driver gathered.  Nothing here lists a cell, a mix or a metric: adding
+warms up, measures, and checks its answers against the reference.  The
+solve driver builds the instance family that the configuration's
+``family`` names, ``bench/families/<family>.py``.  Each per-layer metric
+is read by ``bench/metrics/<metric>.py`` from the facts the driver
+gathered.  Nothing here lists a cell, a mix, a family or a metric: adding
 one is adding files and entries.
 """
 

@@ -1,8 +1,9 @@
 """The plain reference, and the lower-precision control.
 
-The reference reads the benchmark's own tables (made by :mod:`bench.gen`)
-and an answer ``(v, policy)`` that the program returned, and evaluates the
-answer exactly, in float64 on the host with numpy:
+The reference reads the benchmark's own tables, as ELL row blocks that
+the cell's family (``bench/families/``) hands it, and an answer ``(v,
+policy)`` that the program returned, and evaluates the answer exactly, in
+float64 on the host with numpy:
 
 * ``residual`` — ``max_s |min_a Q(v)[s, a] - v[s]|``, the Bellman residual
   that the program certifies to be at most ``atol``;

@@ -90,6 +90,19 @@ def test_config_resolves(conf):
     assert files.count(c["file"]) == 1
 
 
+@pytest.mark.parametrize("conf", [c["name"] for c in SPEC["configs"]])
+def test_config_names_a_family_file(conf):
+    """The solve driver builds the family that the configuration's
+    ``family`` names, ``bench/families/<family>.py``, which defines
+    ``table``."""
+    c = next(x for x in SPEC["configs"] if x["name"] == conf)
+    with open(os.path.join(ROOT, c["file"])) as f:
+        family = json.load(f)["family"]
+    assert NAME.match(family)
+    with open(os.path.join(BENCH, "families", family + ".py")) as f:
+        assert re.search(r"^def table\(", f.read(), re.M)
+
+
 @pytest.mark.parametrize("metric", [m["name"] for m in METRICS])
 def test_metric_shape(metric):
     m = next(x for x in METRICS if x["name"] == metric)

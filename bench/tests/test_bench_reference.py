@@ -10,6 +10,7 @@ import numpy as np
 import pytest
 
 from bench import gen, reference
+from bench.families.garnet import garnet
 
 BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -34,7 +35,7 @@ def _dense_check(idx, val, cost, gamma, v, pi):
 
 
 def test_evaluate_matches_loops():
-    idx, val, cost = (np.asarray(a) for a in gen.garnet(5, 64, 4, 3))
+    idx, val, cost = (np.asarray(a) for a in garnet(5, 64, 4, 3))
     rng = np.random.default_rng(0)
     v = rng.random(64).astype(np.float32) * 10
     pi = rng.integers(0, 4, 64).astype(np.int32)
@@ -45,7 +46,7 @@ def test_evaluate_matches_loops():
 
 
 def test_evaluate_in_row_blocks(monkeypatch):
-    idx, val, cost = (np.asarray(a) for a in gen.garnet(6, 100, 3, 2))
+    idx, val, cost = (np.asarray(a) for a in garnet(6, 100, 3, 2))
     v = np.linspace(0, 5, 100).astype(np.float32)
     pi = np.zeros(100, np.int32)
     whole = reference.evaluate([(0, idx, val, cost)], 0.8, v, pi, 100)
@@ -56,7 +57,7 @@ def test_evaluate_in_row_blocks(monkeypatch):
 
 
 def test_evaluate_rejects_missing_actions_and_nan():
-    idx, val, cost = (np.asarray(a) for a in gen.garnet(7, 16, 2, 2))
+    idx, val, cost = (np.asarray(a) for a in garnet(7, 16, 2, 2))
     v = np.zeros(16, np.float32)
     pi = np.full(16, 2, np.int32)               # action 2 of 2 does not exist
     got = reference.evaluate([(0, idx, val, cost)], 0.9, v, pi, 16)
@@ -68,23 +69,23 @@ def test_evaluate_rejects_missing_actions_and_nan():
 
 
 def test_generator_is_a_function_of_the_seed(monkeypatch):
-    a = [np.asarray(x) for x in gen.garnet(3000000001, 256, 4, 3)]
-    b = [np.asarray(x) for x in gen.garnet(3000000001, 256, 4, 3)]
-    c = [np.asarray(x) for x in gen.garnet(3000000001 + (1 << 32), 256, 4, 3)]
+    a = [np.asarray(x) for x in garnet(3000000001, 256, 4, 3)]
+    b = [np.asarray(x) for x in garnet(3000000001, 256, 4, 3)]
+    c = [np.asarray(x) for x in garnet(3000000001 + (1 << 32), 256, 4, 3)]
     for x, y in zip(a, b):
         np.testing.assert_array_equal(x, y)
     assert not np.array_equal(a[0], c[0])
     # rows do not depend on the chunking
     monkeypatch.setattr(gen, "CHUNK_ROWS", 64)
-    gen._single.cache_clear()
-    d = [np.asarray(x) for x in gen.garnet(3000000001, 256, 4, 3)]
-    gen._single.cache_clear()
+    gen._program.cache_clear()
+    d = [np.asarray(x) for x in garnet(3000000001, 256, 4, 3)]
+    gen._program.cache_clear()
     for x, y in zip(a, d):
         np.testing.assert_array_equal(x, y)
 
 
 def test_generator_table_is_a_garnet():
-    idx, val, cost = (np.asarray(a) for a in gen.garnet(9, 512, 4, 8))
+    idx, val, cost = (np.asarray(a) for a in garnet(9, 512, 4, 8))
     assert idx.dtype == np.int32 and val.dtype == np.float32
     assert idx.min() >= 0 and idx.max() < 512 and len(np.unique(idx)) > 400
     np.testing.assert_allclose(val.sum(-1), 1.0, atol=1e-6)
@@ -99,9 +100,9 @@ def _renaming(plain, relabeled):
 
 
 def test_relabeling_renames_the_states(monkeypatch):
-    plain = [np.asarray(x) for x in gen.garnet(11, 256, 4, 3)]
-    one = [np.asarray(x) for x in gen.garnet(11, 256, 4, 3,
-                                             relabel=3000000901 + (1 << 33))]
+    plain = [np.asarray(x) for x in garnet(11, 256, 4, 3)]
+    one = [np.asarray(x) for x in garnet(11, 256, 4, 3,
+                                         relabel=3000000901 + (1 << 33))]
     perm = _renaming(plain, one)
     assert sorted(perm) == list(range(256))
     assert (perm != np.arange(256)).mean() > 0.9
@@ -109,13 +110,13 @@ def test_relabeling_renames_the_states(monkeypatch):
     np.testing.assert_array_equal(one[1][perm], plain[1])
     np.testing.assert_array_equal(one[2][perm], plain[2])
     # another seed renames otherwise; the same seed, in chunks, alike
-    other = [np.asarray(x) for x in gen.garnet(11, 256, 4, 3, relabel=5)]
+    other = [np.asarray(x) for x in garnet(11, 256, 4, 3, relabel=5)]
     assert not np.array_equal(_renaming(plain, other), perm)
     monkeypatch.setattr(gen, "CHUNK_ROWS", 64)
-    gen._single.cache_clear()
-    again = [np.asarray(x) for x in gen.garnet(
+    gen._program.cache_clear()
+    again = [np.asarray(x) for x in garnet(
         11, 256, 4, 3, relabel=3000000901 + (1 << 33))]
-    gen._single.cache_clear()
+    gen._program.cache_clear()
     for x, y in zip(one, again):
         np.testing.assert_array_equal(x, y)
 
@@ -127,8 +128,8 @@ def test_relabeled_instances_take_the_same_work():
 
     cfg = _config("garnet_1m")
     n = 2048
-    tables = [gen.garnet(cfg["instance_seed"], n, cfg["m"], cfg["k"],
-                         relabel=seed)
+    tables = [garnet(cfg["instance_seed"], n, cfg["m"], cfg["k"],
+                     relabel=seed)
               for seed in (None, 3000000911, 3000000912 + (1 << 40))]
     results = []
     with Session({"-method": cfg["method"], "-dtype": cfg["dtype"],
@@ -153,7 +154,7 @@ def test_control_fails_where_the_program_passes(config, n):
     from repro.api import MDP, Session
 
     cfg = _config(config)
-    table = gen.garnet(11, n, cfg["m"], cfg["k"])
+    table = garnet(11, n, cfg["m"], cfg["k"])
     mdp = MDP.from_arrays(idx=table[0], val=table[1], cost=table[2],
                           gamma=cfg["gamma"], validate=False)
     with Session({"-method": cfg["method"], "-dtype": "float32",
